@@ -164,29 +164,37 @@ def detector_microbench(n: int, windows: int = 24,
 def scorer_agreement(n: int, windows: int = 6,
                      n_stragglers: int = 4) -> dict:
     """Drive two detectors — NumPy reference scorer vs the pallas
-    fleet-score kernel — over the same frames; every verdict array must
-    agree bit-identically (the kernel's golden contract, checked at
-    fleet scale where lane padding and big-N medians actually bite)."""
+    fleet-score kernel — over the same frames. Every verdict array must
+    agree exactly (the kernel's golden contract, checked at fleet scale
+    where lane padding and big-N medians actually bite); ``differ`` names
+    any that do not. The slowdown magnitude is ``x / median - 1``
+    averaged over windows: ``slowdown_ulps`` is its largest difference in
+    ulps of that quotient — 0 wherever float32 division rounds as IEEE
+    does (the CPU), not necessarily on a TPU. ``bit_identical`` asks for
+    both."""
     rng = np.random.RandomState(n + 1)
     stragglers = _stragglers(n, n_stragglers)
     det_ref = StragglerDetector(DetectorConfig(scorer="numpy"))
     det_pl = StragglerDetector(DetectorConfig(scorer="pallas"))
-    agree = True
+    differ, ulps = set(), 0.0
     for w in range(windows):
         frame = synthetic_frame(w, n, rng, stragglers)
         a = det_ref.update(copy.deepcopy(frame))
         b = det_pl.update(copy.deepcopy(frame))
-        agree &= np.array_equal(a.flagged, b.flagged)
-        agree &= np.array_equal(a.slowdown, b.slowdown)
-        agree &= np.array_equal(a.stalled, b.stalled)
-        agree &= np.array_equal(a.step_deviant, b.step_deviant)
-        agree &= set(a.support_masks) == set(b.support_masks)
-        for m in a.support_masks:
-            agree &= np.array_equal(a.support_masks[m],
-                                    b.support_masks.get(m))
-        if not agree:
-            break
-    return {"n_nodes": n, "windows": windows, "bit_identical": bool(agree)}
+        pairs = [("flagged", a.flagged, b.flagged),
+                 ("stalled", a.stalled, b.stalled),
+                 ("step_deviant", a.step_deviant, b.step_deviant)]
+        pairs += [(m, a.support_masks[m], b.support_masks.get(m))
+                  for m in a.support_masks]
+        differ |= {name for name, x, y in pairs
+                   if y is None or not np.array_equal(x, y)}
+        differ |= set(b.support_masks) - set(a.support_masks)
+        quot_ulp = np.float32(2.0 ** -23) * (1.0 + np.abs(a.slowdown))
+        ulps = max(ulps, float(np.max(np.abs(a.slowdown - b.slowdown)
+                                      / quot_ulp)))
+    return {"n_nodes": n, "windows": windows, "differ": sorted(differ),
+            "slowdown_ulps": ulps, "flagged": int(a.flagged.sum()),
+            "bit_identical": not differ and ulps == 0.0}
 
 
 def sharded_detection(n: int = 16384, windows: int = 8,

@@ -9,11 +9,13 @@ local JAX device(s) plus pairwise bandwidth timing. Instead of wiring
 verification (``replace_node``), the non-blocking scheduler runs the
 sweep -> (if needed) triage -> sweep qualification loop, and every state
 transition — quarantine, sweep start/finish, triage stages, the final
-verdict — arrives as typed events on the session bus. On a real TPU
-host, drop interpret=True for the compiled kernel.
+verdict — arrives as typed events on the session bus. The kernel is
+compiled on a TPU host and interpreted on the CPU backend.
 
 Run:  PYTHONPATH=src python examples/node_sweep_demo.py
 """
+
+import jax
 
 from repro.core.sweep import SweepConfig
 from repro.core.triage import ErrorSignals
@@ -40,10 +42,10 @@ def main():
     # no-evidence early termination (which would RMA the host)
     control.signals_provider = lambda nid: ErrorSignals(
         gpu_errors=True, detail="operator-reported compute suspicion")
-    backend = LocalJaxSweepBackend(interpret=True)
+    backend = LocalJaxSweepBackend()
     ref = backend.reference()
     print(f"[sweep] reference: {ref.device_tflops:.3f} TFLOP/s "
-          f"(interpret-mode on CPU; compiled on TPU), "
+          f"on {jax.default_backend()}, "
           f"{ref.intra_bw_gbps:.1f} GB/s")
 
     cfg = SweepConfig(burn_seconds=16.0, compute_tolerance=0.25,

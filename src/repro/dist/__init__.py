@@ -1,9 +1,9 @@
 """Distribution layer: logical-axis sharding over jax meshes."""
 from repro.dist.api import (DEFAULT_RULES, DistContext, constraint, current,
                             gather_fsdp, param_sharding, set_context,
-                            shard_map, use_mesh)
+                            use_mesh)
 
 __all__ = [
     "DEFAULT_RULES", "DistContext", "constraint", "current", "gather_fsdp",
-    "param_sharding", "set_context", "shard_map", "use_mesh",
+    "param_sharding", "set_context", "use_mesh",
 ]

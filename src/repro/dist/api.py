@@ -31,7 +31,6 @@ into an all-gather right before use).
 from __future__ import annotations
 
 import contextlib
-import inspect
 import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -169,22 +168,6 @@ def constraint(x, *logical_axes: Optional[str]):
         return x
     return jax.lax.with_sharding_constraint(
         x, ctx.sharding(logical_axes, x.shape))
-
-
-# Top-level jax.shard_map (and its check_vma kwarg) only exist on newer
-# jax; 0.4.x has jax.experimental.shard_map.shard_map with the same
-# semantics under check_rep. Resolve both once at import time.
-_shard_map_fn = getattr(jax, "shard_map", None)
-if _shard_map_fn is None:
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-_CHECK_KWARG = ("check_vma" if "check_vma" in
-                inspect.signature(_shard_map_fn).parameters else "check_rep")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``jax.shard_map``."""
-    return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, **{_CHECK_KWARG: check_vma})
 
 
 # ------------------------------------------------------------ param trees

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels for the compute hot-spots, validated in interpret mode:
+"""Pallas TPU kernels for the compute hot-spots, compiled on an accelerator
+and interpreted on the CPU backend (``backend.pallas_interpret``):
 
   flash_attention  tiled online-softmax causal/full GQA attention (prefill)
   wkv6             chunked RWKV6 linear-attention recurrence

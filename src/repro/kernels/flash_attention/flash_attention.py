@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import pallas_interpret
+
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
 _NEG_INF = -1e30
@@ -80,8 +82,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = DEFAULT_BQ, block_k: int = DEFAULT_BK,
-                    q_offset: int = 0, kv_len: int = 0,
-                    interpret: bool = True):
+                    q_offset: int = 0, kv_len: int = 0):
     """q (B, Hq, S, hd); k/v (B, Hkv, T, hd) -> (B, Hq, S, hd).
 
     GQA: query head h reads kv head h // (Hq // Hkv). Requires S % block_q
@@ -118,5 +119,5 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, 128), jnp.float32),     # running denominator
             pltpu.VMEM((bq, hd), jnp.float32),      # output accumulator
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(q, k, v)

@@ -33,10 +33,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import pallas_interpret
 
 # numpy scalars (not jnp 0-d arrays): they inline as jaxpr literals, so
 # the Pallas trace captures no constants
 _SIGN = np.uint32(0x80000000)
+_DEFAULT_SCOPED_VMEM = 16 * 2**20     # Mosaic's scoped-VMEM default
 
 
 def float_key(x: jnp.ndarray) -> jnp.ndarray:
@@ -126,7 +130,8 @@ def score_rows_jnp(
     ms = jnp.maximum(med[:, st_j:st_j + 1], f32(1e-9))     # (R, 1, 1)
     rel = xs / ms - f32(1.0)
     sdev = (dev[:, st_j:st_j + 1] > 0) & (rel > f32(slowdown_floor))
-    dev = dev.at[:, st_j:st_j + 1].set(sdev.astype(jnp.float32))
+    row = jax.lax.broadcasted_iota(jnp.int32, dev.shape, 1) == st_j
+    dev = jnp.where(row, sdev.astype(jnp.float32), dev)
     contrib = jnp.where(sdev, rel, f32(0.0))
     return dev, rel, contrib
 
@@ -152,12 +157,35 @@ def fleet_score(
     slowdown_floor: float,
     mad_floor_frac: float,
     n_valid: Optional[int] = None,
-    interpret: bool = True,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Pallas entry point: grid over R rows, one fused (M, N) block per
-    step resident in VMEM (8 metrics x 131k nodes fp32 ~ 4 MB). The lane
-    dim should be padded to the 128-lane tile with float32 NaN and the
-    true node count passed as ``n_valid``."""
+    step resident in VMEM. Compiled on an accelerator, interpreted on the
+    CPU backend. The lane dim should be padded to the 128-lane tile with
+    float32 NaN and the true node count passed as ``n_valid``."""
+    return _fleet_score_call(
+        mats, dirs, st_j, z_threshold=z_threshold,
+        slowdown_floor=slowdown_floor, mad_floor_frac=mad_floor_frac,
+        n_valid=n_valid, interpret=pallas_interpret())
+
+
+def _vmem_limit_bytes(m: int, n: int) -> int:
+    """Scoped-VMEM budget for one (1, M, N) grid step.
+
+    The whole node row must be resident: every bisection round counts
+    ranks over all N. The kernel holds the (M, N) input block and the
+    (M, N) verdict block plus two (1, N) outputs, each double-buffered,
+    and the compiler's scratch for keys and deviations, which stays under
+    four more (M, N) blocks. At 8 metrics x 131,072 nodes that is
+    2 x 9 MiB of buffers + 16 MiB of scratch = 34 MiB, where the TPU
+    compiler asks for 21.5 MiB with one row and 30.5 MiB with several:
+    above the 16 MiB default, inside v5e's 128 MiB of VMEM."""
+    block = m * n * 4
+    buffers = 2 * (2 * block + 2 * n * 4)
+    return max(_DEFAULT_SCOPED_VMEM, buffers + 4 * block)
+
+
+def _fleet_score_call(mats, dirs, st_j, *, z_threshold, slowdown_floor,
+                      mad_floor_frac, n_valid, interpret: bool):
     r, m, n = mats.shape
     dirs_arr = np.asarray(dirs, np.float32).reshape(m, 1)
     kernel = functools.partial(
@@ -176,6 +204,8 @@ def fleet_score(
         out_shape=[jax.ShapeDtypeStruct((r, m, n), jnp.float32),
                    jax.ShapeDtypeStruct((r, 1, n), jnp.float32),
                    jax.ShapeDtypeStruct((r, 1, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(m, n)),
         interpret=interpret,
     )(mats, dirs_arr)
 

@@ -10,13 +10,17 @@
            constrained over the ``fleet_node`` logical axis, so the
            peer-median rank counts psum across node shards and the
            elementwise verdicts stay fully partitioned.
-  pallas   the fused Pallas kernel (interpret-mode CPU fallback), lane
-           dim NaN-padded to the 128 tile.
+  pallas   the fused Pallas kernel, compiled on an accelerator and
+           interpreted on the CPU backend; lane dim NaN-padded to the
+           128 tile.
 
-All three agree bit-for-bit on the verdict masks and, for non-degenerate
-inputs, on the continuous outputs (same correctly-rounded float32 ops in
-the same order) — the golden sweep in ``tests/test_detector_golden.py``
-pins that contract.
+All three agree bit-for-bit on the verdict masks. On the CPU they also
+agree, for non-degenerate inputs, on the continuous outputs (same
+correctly-rounded float32 ops in the same order) — the golden sweep in
+``tests/test_detector_golden.py`` pins that contract. TPU float32
+division need not round as IEEE does, so there the relative excess
+``x / median - 1`` may differ from NumPy's in its last bits;
+``chip_smoke.py`` bounds it.
 """
 from __future__ import annotations
 
@@ -55,7 +59,7 @@ def _compiled(backend: str, shape: Tuple[int, int, int],
             return score_rows_jnp(mats, dirs, st_j, **kw)
     else:
         def run(mats):
-            return fleet_score(mats, dirs, st_j, interpret=True, **kw)
+            return fleet_score(mats, dirs, st_j, **kw)
     return jax.jit(run)
 
 

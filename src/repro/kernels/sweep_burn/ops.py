@@ -16,13 +16,12 @@ from repro.kernels.sweep_burn.sweep_burn import burn, burn_flops
 
 
 def measure_tflops(m: int = 512, k: int = 512, iters: int = 64,
-                   repeats: int = 3, interpret: bool = True,
-                   seed: int = 0) -> float:
+                   repeats: int = 3, seed: int = 0) -> float:
     """Median sustained TFLOP/s of the burn chain on the local device."""
     key = jax.random.key(seed)
     a = jax.random.normal(key, (m, k), jnp.float32)
     b = jax.random.normal(jax.random.fold_in(key, 1), (k, k), jnp.float32)
-    f = jax.jit(lambda a, b: burn(a, b, iters=iters, interpret=interpret))
+    f = jax.jit(lambda a, b: burn(a, b, iters=iters))
     f(a, b).block_until_ready()                   # compile/warm
     ts = []
     for _ in range(repeats):
@@ -37,9 +36,7 @@ class LocalJaxSweepBackend:
     Pallas burn kernel; bandwidth probes time a device round-trip copy.
     Used by examples/node_sweep_demo.py."""
 
-    def __init__(self, reference: Optional[SweepReference] = None,
-                 interpret: bool = True):
-        self._interpret = interpret
+    def __init__(self, reference: Optional[SweepReference] = None):
         self._ref = reference
 
     def device_count(self, node_id: int) -> int:
@@ -47,9 +44,9 @@ class LocalJaxSweepBackend:
 
     def compute_probe(self, node_id: int, device: int,
                       seconds: float) -> float:
-        iters = max(8, min(int(seconds), 64))
-        return measure_tflops(iters=iters, interpret=self._interpret,
-                              seed=device)
+        # whole kernel blocks of 8 chained products, 8 to 64 of them
+        iters = 8 * max(1, min(int(seconds), 64) // 8)
+        return measure_tflops(iters=iters, seed=device)
 
     def intra_bw_probe(self, node_id: int, dev_a: int, dev_b: int) -> float:
         x = jnp.ones((4 << 20,), jnp.float32)      # 16 MB
@@ -78,7 +75,7 @@ class LocalJaxSweepBackend:
     def reference(self) -> SweepReference:
         if self._ref is None:
             # self-calibrate: current device defines "healthy"
-            tf = measure_tflops(interpret=self._interpret)
+            tf = measure_tflops()
             bw = self.intra_bw_probe(0, 0, 1)
             st = float(np.median(self.multi_node_probe([0, 1], 5)))
             self._ref = SweepReference(tf, bw, st)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import pallas_interpret
+
 
 def _burn_kernel(a_ref, b_ref, o_ref, acc, *, iters_per_block: int):
     i = pl.program_id(0)
@@ -44,12 +46,17 @@ def _burn_kernel(a_ref, b_ref, o_ref, acc, *, iters_per_block: int):
         o_ref[...] = acc[...]
 
 
-def burn(a, b, *, iters: int = 64, iters_per_block: int = 8,
-         interpret: bool = True):
-    """a (M, K), b (K, N) fp32 -> (M, N) chained product.
+def burn(a, b, *, iters: int = 64, iters_per_block: int = 8):
+    """a (M, K), b (K, N) fp32 -> (M, N) chained product. Compiled on an
+    accelerator, interpreted on the CPU backend.
 
     FLOPs executed = 2 * M * K * N * iters (requires K == N for chaining).
     """
+    return _burn_call(a, b, iters=iters, iters_per_block=iters_per_block,
+                      interpret=pallas_interpret())
+
+
+def _burn_call(a, b, *, iters: int, iters_per_block: int, interpret: bool):
     M, K = a.shape
     K2, N = b.shape
     assert K == K2 == N, "burn chain needs square b"

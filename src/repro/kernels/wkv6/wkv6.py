@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import pallas_interpret
+
 _CLAMP = 25.0
 
 
@@ -73,8 +75,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
         sout_ref[0, 0] = S_new
 
 
-def wkv6_chunked(r, k, v, logw, u, s0, *, chunk: int = 64,
-                 interpret: bool = True):
+def wkv6_chunked(r, k, v, logw, u, s0, *, chunk: int = 64):
     """r/k/v (B, H, S, hd); logw (B, H, S, hd) fp32; u (H, hd);
     s0 (B, H, hd, hd) fp32 -> (y (B, H, S, hd) fp32, s (B, H, hd, hd))."""
     B, H, S, hd = r.shape
@@ -105,6 +106,6 @@ def wkv6_chunked(r, k, v, logw, u, s0, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(r, k, v, logw, u, s0)
     return y, sout

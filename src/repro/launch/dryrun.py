@@ -1,7 +1,12 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines: jax locks the device count on first init.
-#   Only the dry-run sees 512 placeholder devices; tests/benches see 1.
+
+_DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count="
+os.environ["XLA_FLAGS"] = " ".join(
+    [f for f in os.environ.get("XLA_FLAGS", "").split()
+     if not f.startswith(_DEVICE_COUNT_FLAG)] + [_DEVICE_COUNT_FLAG + "512"])
+# ^ MUST run before jax initializes a backend: the device count is fixed
+#   at first init. Only the dry-run sees 512 placeholder devices, and it
+#   keeps every other XLA flag the caller set.
 
 """Multi-pod dry-run: prove the distribution config is coherent without
 hardware.
@@ -30,6 +35,11 @@ import time
 from typing import Optional
 
 import jax
+
+# the dry-run compiles for CPU placeholder devices and never takes a chip:
+# pinned before any backend starts, in this process and (through this same
+# module) in every --all child
+jax.config.update("jax_platforms", "cpu")
 
 from repro.configs import applicable_shapes, get_config, get_shape, \
     list_configs

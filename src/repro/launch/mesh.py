@@ -15,13 +15,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there,
-    # so omitting axis_types keeps identical semantics on both sides.
-    try:
-        types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=types)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    # Auto axes: GSPMD propagates shardings from the annotated params
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -283,7 +283,7 @@ def decode_attention(q, kcache, vcache, k_new, v_new, pos, *,
     dp_size = math.prod(mesh.shape[a] for a in dp) if dp else 1
     bspec = tuple(dp) if (dp and B % dp_size == 0) else None
 
-    fn = dist.shard_map(
+    fn = jax.shard_map(
         functools.partial(_decode_inner, axis=axis,
                           window_offset=window_offset),
         mesh=mesh,

@@ -165,7 +165,7 @@ def apply_moe(p, cfg, x):
         cap = max(int(math.ceil(T_local * m.top_k * m.capacity_factor
                                 / m.num_experts)), m.top_k)
 
-        fn = dist.shard_map(
+        fn = jax.shard_map(
             functools.partial(
                 _dispatch_compute_combine, act=cfg.act, capacity=cap,
                 num_experts=m.num_experts, top_k=m.top_k, ep_axis=ep_axis),

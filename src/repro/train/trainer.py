@@ -118,18 +118,29 @@ class Trainer:
         self.history: list = []
         self.last_recovery: Optional[Dict[str, Any]] = None
 
-        self.params, self.axes = model.init_params(jax.random.key(seed))
-        self.opt_state = init_opt_state(self.params)
-        self._step_fn = self._build_step()
-
-    def _build_step(self):
-        step = make_train_step(self.model, self.cfg.opt, self.cfg.microbatch)
+        self.axes = model.param_axes()
+        p_sh = o_sh = None
         ctx = dist.current()
-        if ctx is None:
-            return jax.jit(step)
-        p_sh = dist.param_sharding(self.axes, self.params, ctx)
-        o_sh = {"mu": p_sh, "nu": p_sh,
-                "count": ctx.sharding((), ())}
+        if ctx is not None:
+            p_sh = dist.param_sharding(self.axes, model.param_shapes(), ctx)
+            o_sh = {"mu": p_sh, "nu": p_sh, "count": ctx.sharding((), ())}
+        # initialize where the state lives: under a mesh every leaf is
+        # born with its own sharding instead of whole on device 0
+        self.params = jax.jit(lambda key: model.init_params(key)[0],
+                              out_shardings=p_sh)(jax.random.key(seed))
+        self.opt_state = jax.jit(init_opt_state,
+                                 out_shardings=o_sh)(self.params)
+        self._step_fn = self._build_step(p_sh, o_sh)
+
+    def _build_step(self, p_sh, o_sh):
+        """Params and optimizer state are donated with or without a mesh:
+        the step's outputs reuse their buffers, so the state is held once
+        on the device, not twice. Snapshots copy to the host first
+        (``TieredCheckpointManager.save_fast``), and a restore hands in
+        host arrays, so nothing reads a donated buffer."""
+        step = make_train_step(self.model, self.cfg.opt, self.cfg.microbatch)
+        if p_sh is None:
+            return jax.jit(step, donate_argnums=(0, 1))
         b_sh = None  # batch sharding constrained inside the model
         return jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
                        out_shardings=(p_sh, o_sh, None),

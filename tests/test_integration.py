@@ -46,7 +46,7 @@ class TestLocalJaxBackend:
     def test_real_sweep_on_local_device(self):
         """The deployable path: the §5.2 sweep driving the actual Pallas
         burn kernel on this host's device."""
-        backend = LocalJaxSweepBackend(interpret=True)
+        backend = LocalJaxSweepBackend()
         ref = backend.reference()
         assert ref.device_tflops > 0
         rep = single_node_sweep(

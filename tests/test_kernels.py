@@ -1,5 +1,5 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp oracles
-(interpret=True executes the Pallas bodies on CPU)."""
+(on the CPU backend the Pallas bodies run interpreted)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
