@@ -28,6 +28,7 @@ from typing import Dict, Iterator, List, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core.telemetry import (HARDWARE_METRICS, METRIC_DIRECTION, Frame,
                                   RingHistory)
 from repro.kernels.fleet_score import score_rows
@@ -224,6 +225,7 @@ class StragglerDetector:
             rows = np.arange(len(hist))      # backfill rescored everything
         else:
             rows = np.asarray([hist.last_row])
+        tracing.count("detector.rows_scored", len(rows))
         self._score_rows(rows)
 
     def _realign_state(self, node_ids: np.ndarray) -> None:
@@ -256,9 +258,17 @@ class StragglerDetector:
         self._state_ids = node_ids.copy()
 
     def update(self, frame: Frame) -> FleetAssessment:
+        with tracing.span("detector.push"):
+            self.history.push(frame)
+        with tracing.span("detector.score"):
+            self._sync_scores()
+        with tracing.span("detector.verdicts"):
+            return self._verdicts(frame)
+
+    def _verdicts(self, frame: Frame) -> FleetAssessment:
+        """Counts, slowdown, stalls, support and the latch over the
+        scored history, for the frame just pushed."""
         cfg = self.cfg
-        self.history.push(frame)
-        self._sync_scores()
         depth = len(self.history)
         used = slice(0, depth)
         # "sustained" requires a full persistence window of history; until

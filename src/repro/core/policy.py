@@ -18,6 +18,7 @@ import dataclasses
 import enum
 from typing import List, Optional, Sequence, Union
 
+from repro import tracing
 from repro.core.detector import FleetAssessment, NodeAssessment
 
 
@@ -49,10 +50,12 @@ class TieredPolicy:
     def decide(self, assessments: Union[FleetAssessment,
                                         Sequence[NodeAssessment]]
                ) -> List[Decision]:
-        if isinstance(assessments, FleetAssessment):
-            return self._decide_fleet(assessments)
-        return [self._decide_one(a.node_id, a.slowdown, a.stalled, a.support)
-                for a in assessments if a.flagged]
+        with tracing.span("policy.tiers"):
+            if isinstance(assessments, FleetAssessment):
+                return self._decide_fleet(assessments)
+            return [self._decide_one(a.node_id, a.slowdown, a.stalled,
+                                     a.support)
+                    for a in assessments if a.flagged]
 
     def _decide_fleet(self, fleet: FleetAssessment) -> List[Decision]:
         """Vectorized tier classification over the assessment arrays:

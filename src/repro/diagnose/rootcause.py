@@ -46,11 +46,11 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core.detector import FleetAssessment
 from repro.core.policy import Action, Decision
 from repro.core.telemetry import Frame
@@ -184,7 +184,6 @@ class Diagnoser:
         self._emitted: Dict[int, RootCause] = {}
         self.last_fleet: Optional[FleetDiagnosis] = None
         self.windows_diagnosed = 0
-        self.last_cost_s = 0.0
         # per-row comm-deviation cache aligned with the trace's circular
         # buffer: a row's peer-median comparison never changes once
         # written, so steady state re-medians one new row per window
@@ -216,7 +215,13 @@ class Diagnoser:
             self.last_fleet = None
             return None
 
-        t0 = time.perf_counter()
+        with tracing.span("diagnose.attribute"):
+            return self._attribute(frame, fleet, flagged_idx)
+
+    def _attribute(self, frame: Frame, fleet: FleetAssessment,
+                   flagged_idx: np.ndarray) -> FleetDiagnosis:
+        """What-if blame and root-cause records for the flagged rows."""
+        trace = self.trace
         cfg = self.cfg
         comp = trace.mean("compute")
         comm = trace.mean("comm")
@@ -328,7 +333,6 @@ class Diagnoser:
                              new_records)
         self.last_fleet = out
         self.windows_diagnosed += 1
-        self.last_cost_s = time.perf_counter() - t0
         return out
 
     def _comm_sustain(self) -> np.ndarray:

@@ -37,6 +37,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.ccltrace.watchdog import adaptive_deadline
 from repro.core.detector import DetectorConfig
 from repro.core.sweep import SweepReference
@@ -249,6 +250,11 @@ class GuardStepHook:
         self._hw_counts.clear()
 
     def __call__(self, step: int, wall_s: float,
+                 metrics: Dict[str, float]) -> bool:
+        with tracing.span("guard.hook"):
+            return self._on_step(step, wall_s, metrics)
+
+    def _on_step(self, step: int, wall_s: float,
                  metrics: Dict[str, float]) -> bool:
         if self._restart_pending:
             # deferred swaps landed at the last checkpoint: the manager

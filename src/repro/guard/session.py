@@ -32,6 +32,7 @@ from typing import Callable, List, Optional, Sequence, Set
 
 import numpy as np
 
+from repro import tracing
 from repro.core.detector import DetectorConfig
 from repro.core.health_manager import (ClusterControl, HealthManager,
                                        ManagerStats, NodeState)
@@ -211,6 +212,12 @@ class GuardSession:
         out = WindowOutcome([], [], [], [])
         if not self.online_monitoring:
             return out
+        with tracing.span("guard.observe"):
+            self._observe(frame, out)
+        tracing.count("guard.windows_scored")
+        return out
+
+    def _observe(self, frame: Frame, out: WindowOutcome) -> None:
         events = self.monitor.observe(frame)
         diag = self.monitor.last_diagnosis
         if diag is not None:
@@ -253,7 +260,6 @@ class GuardSession:
                     self.bus.publish(StragglerCleared(
                         t=frame.t, step=frame.step, node_id=nid))
         self.advance(frame.t)
-        return out
 
     def on_checkpoint(self, now: Optional[float] = None,
                       step: Optional[int] = None) -> CheckpointOutcome:

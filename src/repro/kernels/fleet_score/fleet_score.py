@@ -207,6 +207,7 @@ def _fleet_score_call(mats, dirs, st_j, *, z_threshold, slowdown_floor,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit_bytes(m, n)),
         interpret=interpret,
+        name="fleet_score",
     )(mats, dirs_arr)
 
 

@@ -29,6 +29,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.kernels.fleet_score.ref import score_rows_ref
 
 BACKENDS = ("numpy", "jax", "pallas")
@@ -97,12 +98,20 @@ def score_rows(
         mats = np.pad(mats, ((0, 0), (0, 0), (0, pad)), mode="constant",
                       constant_values=np.float32(np.nan))
         n_valid = n
-    fn = _compiled(backend, mats.shape, tuple(float(v) for v in dirs),
-                   None if st_j is None else int(st_j),
-                   float(z_threshold), float(slowdown_floor),
-                   float(mad_floor_frac), n_valid, current())
-    dev, rel, contrib = (np.asarray(o) for o in fn(mats))
-    return (dev[..., :n] > 0, rel[:, 0, :n], contrib[:, 0, :n])
+    tracing.count("fleet_score.bytes_in", mats.nbytes)
+    with tracing.span("fleet_score.call"):
+        fn = _compiled(backend, mats.shape, tuple(float(v) for v in dirs),
+                       None if st_j is None else int(st_j),
+                       float(z_threshold), float(slowdown_floor),
+                       float(mad_floor_frac), n_valid, current())
+        with tracing.span("fleet_score.dispatch"):
+            outs = fn(mats)
+        with tracing.span("fleet_score.fetch"):
+            dev, rel, contrib = (np.asarray(o) for o in outs)
+            out = (dev[..., :n] > 0, rel[:, 0, :n], contrib[:, 0, :n])
+    tracing.count("fleet_score.bytes_out",
+                  dev.nbytes + rel.nbytes + contrib.nbytes)
+    return out
 
 
 __all__ = ["BACKENDS", "score_rows"]

@@ -42,6 +42,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.guard.goodput import (CheckpointTier, RecoveryModel,
                                  replica_partner, young_daly_interval)
 
@@ -273,20 +274,25 @@ class TieredCheckpointManager(CheckpointManager):
         """Snapshot into both fast tiers: the in-memory peer replica and
         the node-local shard. Same flat layout as the durable tier, so a
         restore from any tier is bit-identical."""
-        flat = {f"p/{k}": v for k, v in _flatten(params).items()}
-        flat.update({f"o/{k}": v for k, v in _flatten(opt_state).items()})
-        # PEER: replica handed to the DP partner; copy so later donated/
-        # mutated buffers can't reach back into the snapshot
-        self._peer = {"step": int(step),
-                      "holder": self.peer_rank,
-                      "flat": {k: np.array(v, copy=True)
-                               for k, v in flat.items()}}
+        with tracing.span("ckpt.snapshot.copy"):
+            flat = {f"p/{k}": v for k, v in _flatten(params).items()}
+            flat.update({f"o/{k}": v
+                         for k, v in _flatten(opt_state).items()})
+            # PEER: replica handed to the DP partner; copy so later
+            # donated/mutated buffers can't reach back into the snapshot
+            self._peer = {"step": int(step),
+                          "holder": self.peer_rank,
+                          "flat": {k: np.array(v, copy=True)
+                                   for k, v in flat.items()}}
         # LOCAL: synchronous atomic write of the node-local shard
         tmp = os.path.join(self.local_dir, f".tmp-fast-{step}")
         final = os.path.join(self.local_dir, f"fast-{step:08d}.npz")
-        with open(tmp, "wb") as f:
-            np.savez(f, **flat)
-        os.replace(tmp, final)
+        with tracing.span("ckpt.snapshot.write"):
+            with open(tmp, "wb") as f:
+                np.savez(f, **flat)
+            os.replace(tmp, final)
+        tracing.count("ckpt.snapshot_bytes",
+                      sum(v.nbytes for v in flat.values()))
         self.snapshots_taken += 1
         self._gc_local()
 

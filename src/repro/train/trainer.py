@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, Optional, Protocol
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.dist import api as dist
 from repro.models.model import Model
 from repro.train.checkpoint import CheckpointManager
@@ -176,39 +177,55 @@ class Trainer:
             ) -> Dict[str, Any]:
         step = self.restore()
         while step < self.cfg.steps:
-            batch = self.data.batch_at(step)
+            with tracing.span("train.batch"):
+                batch = self.data.batch_at(step)
             t0 = time.perf_counter()
-            self.params, self.opt_state, metrics = self._step_fn(
-                self.params, self.opt_state, batch)
-            jax.block_until_ready(metrics["loss"])
+            # on a replayed step the dispatch only enqueues the restored
+            # host state's copy to the device; the wait holds it
+            with tracing.span("train.dispatch"):
+                self.params, self.opt_state, metrics = self._step_fn(
+                    self.params, self.opt_state, batch)
+            with tracing.span("train.wait"):
+                jax.block_until_ready(metrics["loss"])
             wall = time.perf_counter() - t0
             step += 1
-            m = {k: float(v) for k, v in metrics.items()}
-            self.history.append({"step": step, "wall_s": wall, **m})
+            with tracing.span("train.metrics"):
+                m = {k: float(v) for k, v in metrics.items()}
+                self.history.append({"step": step, "wall_s": wall, **m})
             if on_metrics:
                 on_metrics(step, m)
 
+            boundary = bool(self.ckpt) and \
+                step % self.cfg.ckpt_interval == 0
             if self.ckpt:
-                # fast-tier snapshots (tiered manager only): peer replica
-                # + local shard on the MTTF-tuned cadence
-                on_step = getattr(self.ckpt, "on_step", None)
-                if on_step:
-                    on_step(step, self.params, self.opt_state)
+                with tracing.span("train.snapshot"):
+                    # fast-tier snapshots (tiered manager only): peer
+                    # replica + local shard on the MTTF-tuned cadence
+                    on_step = getattr(self.ckpt, "on_step", None)
+                    if on_step:
+                        on_step(step, self.params, self.opt_state)
+                    if boundary:
+                        self.ckpt.save(step, self.params, self.opt_state)
 
-            if self.ckpt and step % self.cfg.ckpt_interval == 0:
-                self.ckpt.save(step, self.params, self.opt_state)
+            if boundary:
                 # checkpoint boundary: Guard lands deferred mitigations
                 # here (the hook may request a restart on the next step)
                 on_ckpt = getattr(self.hook, "on_checkpoint", None)
                 if on_ckpt:
-                    on_ckpt(step)
+                    with tracing.span("train.hook"):
+                        on_ckpt(step)
 
-            if self.hook and self.hook(step, wall, m):
+            restart = False
+            if self.hook:
+                with tracing.span("train.hook"):
+                    restart = self.hook(step, wall, m)
+            if restart:
                 # Guard requested an immediate restart: rewind to the last
                 # checkpoint (replacement happens at the cluster layer)
                 fail_step = step
                 t_restore = time.perf_counter()
-                step = self.restore()
+                with tracing.span("train.restore"):
+                    step = self.restore()
                 restore_wall = time.perf_counter() - t_restore
                 on_restart = getattr(self.hook, "on_restart", None)
                 if on_restart:
