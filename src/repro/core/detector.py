@@ -19,7 +19,9 @@ struct-of-arrays ``FleetAssessment`` whose latch / clean-streak state is
 held as node-indexed arrays, so one 16k-node evaluation window costs a
 fixed number of numpy reductions and O(flagged) Python objects — per-node
 ``NodeAssessment`` records are materialized lazily, and only for the
-consumers that ask for them.
+consumers that ask for them. Deviation counts accumulate in the smallest
+unsigned type that holds the window: NumPy would widen the bool reduction
+to int64 through a buffered cast (~3 ms a frame at 65,536 rows).
 """
 from __future__ import annotations
 
@@ -182,6 +184,8 @@ class StragglerDetector:
         self._dirs: tuple = ()
         self._st_j: Optional[int] = None
         self._rows_mat: Optional[np.ndarray] = None  # (depth, M, N) scratch
+        # counts never exceed the window: uint8 up to 255, uint16 above
+        self._count_t = np.min_scalar_type(self.history.depth)
 
     # ------------------------------------------------------------ core
 
@@ -277,8 +281,9 @@ class StragglerDetector:
         need = cfg.persistence if warmed else depth + 1  # unattainable early
 
         # --- primary signal: sustained relative step-time excess
-        # (one stacked reduction covers every metric's deviation counts)
-        all_counts = self._dev3[:, used].sum(1)          # (M, N)
+        # (one stacked reduction covers every metric's deviation counts,
+        # summed straight off the bool cache in the window's narrow type)
+        all_counts = self._dev3[:, used].sum(1, dtype=self._count_t)  # (M, N)
         dev_count = all_counts[self._st_j]
         step_deviant = dev_count >= need
         # sustained slowdown magnitude: mean over deviant windows. The
@@ -299,7 +304,7 @@ class StragglerDetector:
 
         # --- supporting hardware signals (sustained)
         support_masks = {}
-        support_count = np.zeros(len(frame.node_ids), dtype=int)
+        support_count = np.zeros(len(frame.node_ids), dtype=self._count_t)
         for m in HARDWARE_METRICS:
             if m in self._metric_idx:
                 mask = all_counts[self._metric_idx[m]] >= need

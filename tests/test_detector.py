@@ -1,5 +1,6 @@
 """Unit tests for the peer-relative straggler detector (§4.2)."""
 import numpy as np
+import pytest
 
 from repro.core import (Action, DetectorConfig, OnlineMonitor, PolicyConfig,
                         StragglerDetector, TieredPolicy, robust_z)
@@ -59,6 +60,22 @@ class TestDetector:
         assert by[15].flagged
         assert by[15].step_deviant
         assert 0.15 < by[15].slowdown < 0.25
+        assert not any(a.flagged for a in res if a.node_id != 15)
+
+    @pytest.mark.parametrize("window,persistence", [(6, 3), (300, 260)])
+    def test_sustained_straggler_counted_over_the_window(self, window,
+                                                         persistence):
+        """Deviation counts hold the whole window: at 260 deviant windows
+        a uint8 count would wrap to 4 and drop the straggler."""
+        det = StragglerDetector(DetectorConfig(
+            window=window, persistence=persistence, scorer="numpy"))
+        times = lambda w: [10.0] * 15 + [12.0]
+        res = feed(det, times, persistence - 1)
+        assert not res[15].step_deviant
+        res = det.update(mk_frame(persistence, times(persistence)))
+        assert res[15].flagged and res[15].step_deviant
+        excess = np.float32(12.0) / np.float32(10.0) - np.float32(1.0)
+        assert res[15].slowdown == pytest.approx(float(excess), rel=1e-4)
         assert not any(a.flagged for a in res if a.node_id != 15)
 
     def test_transient_spike_not_flagged(self):
